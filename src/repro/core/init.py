@@ -36,6 +36,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from repro import obs
 from repro.core import metrics
 from repro.kernels import ref
 
@@ -286,10 +287,11 @@ def resolve_init(points: jnp.ndarray, key: jax.Array, k: int, method: str, *,
     if method not in INIT_METHODS or method == "given":
         raise ValueError(f"unknown init method: {method!r} "
                          f"(expected one of {INIT_METHODS[1:]})")
-    if method == "sample":
-        return sample_init(points, key, k)
-    if method == "kmeans++":
-        return kmeans_plus_plus(points, key, k, weights=weights)
-    return kmeans_parallel_init(points, key, k, weights=weights,
-                                backend=backend, spec=spec,
-                                mesh=mesh, axis_names=axis_names)
+    with obs.span("init"):
+        if method == "sample":
+            return sample_init(points, key, k)
+        if method == "kmeans++":
+            return kmeans_plus_plus(points, key, k, weights=weights)
+        return kmeans_parallel_init(points, key, k, weights=weights,
+                                    backend=backend, spec=spec,
+                                    mesh=mesh, axis_names=axis_names)

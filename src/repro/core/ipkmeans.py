@@ -39,6 +39,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from repro import obs
 from repro.core import kdtree, merge, metrics
 from repro.core.kmeans import KMeansParams, KMeansResult, kmeans_batched
 from repro.kernels import engine as engines
@@ -240,36 +241,37 @@ def _partition_and_pack(points, key, cfg: IPKMeansConfig,
     n = points.shape[0]
     capacity = cfg.subset_capacity(n)
     n_pods = mesh.shape[pod_axis] if (mesh is not None and pod_axis) else 1
-    dropped = None
-    if cfg.pack == "sorted" and n == cfg.num_subsets * capacity:
-        subsets, masks = kdtree.pack_subsets_sorted(
-            points, part.subset_ids, cfg.num_subsets, capacity)
-    elif cfg.pack == "a2a" and mesh is not None:
-        if n_pods > 1:
-            # the pod a2a shards capacity over pods, and a pod's share of a
-            # subset fluctuates around capacity/n_pods — provision the
-            # per-pod slice with the same slack-plus-4-sigma headroom the
-            # send buffers use (masked rows are free for the solve)
-            mean = capacity / n_pods
-            cap_loc = max(8, -(-int(mean * 1.3 + 4 * math.sqrt(mean))
-                               // 8) * 8)
-            capacity = cap_loc * n_pods
-        subsets, masks, dropped = kdtree.pack_subsets_a2a(
-            points, part.subset_ids, cfg.num_subsets, capacity,
-            mesh, axis_names, pod_axis=pod_axis)
-    else:
-        if cfg.pack == "a2a":
-            warnings.warn(
-                "pack='a2a' needs a device mesh; using the scatter pack "
-                "(all-reduce-shaped collective) instead",
-                RuntimeWarning, stacklevel=2)
-        subsets, masks = kdtree.pack_subsets(
-            points, part.subset_ids, cfg.num_subsets, capacity)
-    _check_pack_complete(n, masks, dropped, cfg.pack)
-    pad = -subsets.shape[1] % n_pods
-    if pad:
-        subsets = jnp.pad(subsets, ((0, 0), (0, pad), (0, 0)))
-        masks = jnp.pad(masks, ((0, 0), (0, pad)))
+    with jax.named_scope("pack"):
+        dropped = None
+        if cfg.pack == "sorted" and n == cfg.num_subsets * capacity:
+            subsets, masks = kdtree.pack_subsets_sorted(
+                points, part.subset_ids, cfg.num_subsets, capacity)
+        elif cfg.pack == "a2a" and mesh is not None:
+            if n_pods > 1:
+                # the pod a2a shards capacity over pods, and a pod's share of a
+                # subset fluctuates around capacity/n_pods — provision the
+                # per-pod slice with the same slack-plus-4-sigma headroom the
+                # send buffers use (masked rows are free for the solve)
+                mean = capacity / n_pods
+                cap_loc = max(8, -(-int(mean * 1.3 + 4 * math.sqrt(mean))
+                                   // 8) * 8)
+                capacity = cap_loc * n_pods
+            subsets, masks, dropped = kdtree.pack_subsets_a2a(
+                points, part.subset_ids, cfg.num_subsets, capacity,
+                mesh, axis_names, pod_axis=pod_axis)
+        else:
+            if cfg.pack == "a2a":
+                warnings.warn(
+                    "pack='a2a' needs a device mesh; using the scatter pack "
+                    "(all-reduce-shaped collective) instead",
+                    RuntimeWarning, stacklevel=2)
+            subsets, masks = kdtree.pack_subsets(
+                points, part.subset_ids, cfg.num_subsets, capacity)
+        _check_pack_complete(n, masks, dropped, cfg.pack)
+        pad = -subsets.shape[1] % n_pods
+        if pad:
+            subsets = jnp.pad(subsets, ((0, 0), (0, pad), (0, 0)))
+            masks = jnp.pad(masks, ((0, 0), (0, pad)))
     return part, subsets, masks
 
 
@@ -322,10 +324,13 @@ def ipkmeans(points: jnp.ndarray,
     sweeps) before the jitted S1-S3 core runs; ``init_centroids`` may then
     be ``None``.
     """
-    points, init_centroids, key, cfg = _resolve_init_stage(
-        points, init_centroids, key, cfg)
-    res = _ipkmeans_core(points, init_centroids, key, cfg)
-    return res._replace(sse=_total_sse(points, res.centroids))
+    with obs.span("ipkmeans"):
+        points, init_centroids, key, cfg = _resolve_init_stage(
+            points, init_centroids, key, cfg)
+        with obs.span("ipkmeans.core"):
+            res = _ipkmeans_core(points, init_centroids, key, cfg)
+        with obs.span("ipkmeans.sse"):
+            return res._replace(sse=_total_sse(points, res.centroids))
 
 
 @partial(jax.jit, static_argnames=("cfg",))
@@ -334,8 +339,11 @@ def _ipkmeans_core(points: jnp.ndarray,
                    key: jax.Array,
                    cfg: IPKMeansConfig) -> IPKMeansResult:
     part, subsets, masks = _partition_and_pack(points, key, cfg)
-    res = kmeans_batched(subsets, masks, init_centroids, cfg.kmeans)
-    return IPKMeansResult(centroids=_merge_stage(res, cfg), sse=None,
+    with jax.named_scope("s2"):
+        res = kmeans_batched(subsets, masks, init_centroids, cfg.kmeans)
+    with jax.named_scope("s3"):
+        centroids = _merge_stage(res, cfg)
+    return IPKMeansResult(centroids=centroids, sse=None,
                           intermediate=res.centroids, asses=res.asse,
                           subset_iters=res.iters, kd_depth=part.depth)
 

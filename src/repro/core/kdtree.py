@@ -602,7 +602,8 @@ def partition_dataset(points: jnp.ndarray,
     n = points.shape[0]
     cap = num_subsets if leaf_capacity is None else leaf_capacity
     if strategy == "random":
-        ids = random_partition(points, key, num_subsets)
+        with jax.named_scope("s1_label"):
+            ids = random_partition(points, key, num_subsets)
         return Partition(subset_ids=ids,
                          region_ids=jnp.zeros(n, jnp.int32), depth=0)
     depth = required_depth(n, cap)
@@ -618,17 +619,20 @@ def partition_dataset(points: jnp.ndarray,
             raise ValueError(
                 f"sharded partition_dataset supports strategy='kd_axis' "
                 f"only (got {strategy!r})")
-        region = build_kdtree_histogram_sharded(points, depth, mesh,
-                                                tuple(axis_names))
-        ids = label_regions_histogram_sharded(points, region, 2 ** depth,
-                                              num_subsets, mesh,
-                                              tuple(axis_names),
-                                              label_axis=label_axis)
+        with jax.named_scope("s1_tree"):
+            region = build_kdtree_histogram_sharded(points, depth, mesh,
+                                                    tuple(axis_names))
+        with jax.named_scope("s1_label"):
+            ids = label_regions_histogram_sharded(
+                points, region, 2 ** depth, num_subsets, mesh,
+                tuple(axis_names), label_axis=label_axis)
         return Partition(subset_ids=ids, region_ids=region, depth=depth)
     build = build_kdtree_histogram if builder == "histogram" else build_kdtree
-    region = build(points, depth)
+    with jax.named_scope("s1_tree"):
+        region = build(points, depth)
     label_strategy = "axis" if strategy == "kd_axis" else "random"
     label = label_regions_histogram if labeler == "histogram" else label_regions
-    ids = label(points, region, key, 2 ** depth, num_subsets,
-                strategy=label_strategy, label_axis=label_axis)
+    with jax.named_scope("s1_label"):
+        ids = label(points, region, key, 2 ** depth, num_subsets,
+                    strategy=label_strategy, label_axis=label_axis)
     return Partition(subset_ids=ids, region_ids=region, depth=depth)

@@ -64,10 +64,14 @@ def pkmeans(points: jnp.ndarray,
                 engines.get_engine(params.backend), points, w, new_c, counts)
         return (new_c, c, it + 1, metrics.centroid_shift(new_c, c))
 
-    init = (init_centroids, init_centroids, jnp.int32(0), jnp.asarray(jnp.inf))
-    final_c, _, iters, shift = jax.lax.while_loop(cond, body, init)
-    total = metrics.sse(points, final_c, mask)
-    return PKMeansResult(final_c, total, iters, shift <= params.tol)
+    with jax.named_scope("lloyd"):
+        init = (init_centroids, init_centroids, jnp.int32(0),
+                jnp.asarray(jnp.inf))
+        final_c, _, iters, shift = jax.lax.while_loop(cond, body, init)
+        converged = shift <= params.tol
+    with jax.named_scope("sse"):
+        total = metrics.sse(points, final_c, mask)
+    return PKMeansResult(final_c, total, iters, converged)
 
 
 def pkmeans_sharded(mesh,
