@@ -3,9 +3,14 @@
 The paper builds the tree with one MapReduce job per level: reducers split
 each sub-region at the exact median along a cycling axis, appending one bit to
 the region id.  The TPU adaptation keeps the *level-synchronous* schedule but
-replaces the shuffle with a lexicographic sort: one (region, coord) sort per
-level computes every region's exact median split simultaneously.  ``depth``
-levels <=> the paper's O(log n) MapReduce jobs.
+replaces the shuffle with sorts: per level, one (region, coord) sort computes
+every region's exact median split simultaneously, and a second sort, keyed on
+the first one's permutation, returns the new region ids to point order.
+Exact median splits make every region's start and size a function of n and
+the level alone, so a point's rank inside its region follows from its sorted
+position by elementwise integer work (:func:`_median_segments`): no counts,
+no scatter and no gather.  ``depth`` levels <=> the paper's O(log n)
+MapReduce jobs.
 
 Everything is pure jnp and jit-safe for a static ``depth`` / ``num_subsets``,
 and — because sorts and scatters are SPMD-partitionable — runs sharded under
@@ -49,6 +54,29 @@ def _segment_rank(sort_primary: jnp.ndarray, order: jnp.ndarray, num_segments: i
     return sorted_seg, rank, size
 
 
+def _median_step(pos: jnp.ndarray, start: jnp.ndarray, size: jnp.ndarray):
+    """One exact median split of the segment [start, start+size) that holds
+    sorted position ``pos``: the left child takes ceil(size/2).  Returns
+    whether ``pos`` falls in the right child, and that child's start and
+    size."""
+    half = (size + 1) // 2
+    right = (pos - start) >= half
+    return (right, jnp.where(right, start + half, start),
+            jnp.where(right, size - half, half))
+
+
+def _median_segments(n: int, levels: int):
+    """Start and size of the segment that holds each sorted position of n
+    points after ``levels`` exact median splits — the positional form of
+    :func:`_segment_rank` for any tree those splits build."""
+    pos = jnp.arange(n, dtype=jnp.int32)
+    start = jnp.zeros(n, jnp.int32)
+    size = jnp.full(n, n, jnp.int32)
+    for _ in range(levels):
+        _, start, size = _median_step(pos, start, size)
+    return start, size
+
+
 @partial(jax.jit, static_argnames=("depth",))
 def build_kdtree(points: jnp.ndarray, depth: int) -> jnp.ndarray:
     """Assign every point a leaf region id via ``depth`` median-split rounds.
@@ -56,17 +84,22 @@ def build_kdtree(points: jnp.ndarray, depth: int) -> jnp.ndarray:
     Axes cycle x, y, x, y, ... exactly as in the paper's 2-D construction;
     the left child takes ceil(size/2) points ("split at median point").
     Returns (n,) int32 region ids in [0, 2**depth).
+
+    A level is two sorts: a stable (region, coord) sort that carries the
+    point index (ties keep index order, as ``jnp.lexsort``), and a sort on
+    that permutation which returns the new ids to point order.
     """
     n, d = points.shape
+    pos = jnp.arange(n, dtype=jnp.int32)
+    start = jnp.zeros(n, jnp.int32)
+    size = jnp.full(n, n, jnp.int32)
     region = jnp.zeros(n, dtype=jnp.int32)
     for level in range(depth):
-        axis = level % d
-        coord = points[:, axis]
-        order = jnp.lexsort((coord, region))           # sort by region, then coord
-        sorted_seg, rank, size = _segment_rank(region, order, 2 ** level)
-        child = (rank >= (size + 1) // 2).astype(jnp.int32)
-        new_sorted = sorted_seg * 2 + child
-        region = jnp.zeros_like(region).at[order].set(new_sorted)
+        coord = points[:, level % d]
+        sorted_seg, _, order = jax.lax.sort((region, coord, pos), num_keys=2)
+        child, start, size = _median_step(pos, start, size)
+        new_sorted = sorted_seg * 2 + child.astype(jnp.int32)
+        _, region = jax.lax.sort((order, new_sorted), num_keys=1)
     return region
 
 
@@ -275,12 +308,23 @@ def label_regions(points: jnp.ndarray,
       * ``'random'`` — variant (1): random permutation inside the leaf.
 
     Labels wrap mod ``num_subsets`` so leaf capacity need not equal M.
+
+    Precondition: ``region_ids`` are the leaves of a depth-log2(num_regions)
+    exact median-split tree over these same points (from either builder),
+    so a point's rank inside its leaf follows from its sorted position; the
+    labels return to point order by a sort keyed on the permutation.
     """
+    depth = num_regions.bit_length() - 1
+    if num_regions != 1 << depth:
+        raise ValueError(f"num_regions must be a power of two, got {num_regions}")
+    n = region_ids.shape[0]
     key2 = _label_key(points, key, strategy, label_axis)
-    order = jnp.lexsort((key2, region_ids))
-    _, rank, _ = _segment_rank(region_ids, order, num_regions)
-    label_sorted = (rank % num_subsets).astype(jnp.int32)
-    return jnp.zeros_like(region_ids).at[order].set(label_sorted)
+    pos = jnp.arange(n, dtype=jnp.int32)
+    _, _, order = jax.lax.sort((region_ids, key2, pos), num_keys=2)
+    start, _ = _median_segments(n, depth)
+    label_sorted = (pos - start) % num_subsets
+    _, labels = jax.lax.sort((order, label_sorted), num_keys=1)
+    return labels
 
 
 # Number of histogram buckets per region for the sort-free labeler.  256

@@ -1,4 +1,7 @@
 """k-d tree partitioning invariants (paper Algorithms 2-3)."""
+import re
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -142,3 +145,127 @@ def test_pack_a2a_fallback_warns_and_counts():
     with pytest.warns(RuntimeWarning, match="num_subsets=8"):
         kdtree.pack_subsets_a2a(pts, ids[:999] % 8, 8, 128,
                                 FakeMesh(), ("data",))
+
+
+# -- the sort-only S1 against the counting formulation it replaced ----------
+
+def _oracle_segment_rank(sort_primary, order, num_segments):
+    sorted_seg = sort_primary[order]
+    counts = jnp.bincount(sort_primary, length=num_segments)
+    starts = jnp.cumsum(counts) - counts
+    rank = jnp.arange(sort_primary.shape[0]) - starts[sorted_seg]
+    return sorted_seg, rank.astype(jnp.int32), counts[sorted_seg].astype(jnp.int32)
+
+
+@partial(jax.jit, static_argnames=("depth",))
+def _oracle_build(points, depth):
+    """lexsort + bincount + scatter back to point order, per level."""
+    n, d = points.shape
+    region = jnp.zeros(n, dtype=jnp.int32)
+    for level in range(depth):
+        order = jnp.lexsort((points[:, level % d], region))
+        sorted_seg, rank, size = _oracle_segment_rank(region, order, 2 ** level)
+        child = (rank >= (size + 1) // 2).astype(jnp.int32)
+        region = jnp.zeros_like(region).at[order].set(sorted_seg * 2 + child)
+    return region
+
+
+@partial(jax.jit, static_argnames=("num_regions", "num_subsets", "strategy"))
+def _oracle_label(points, region_ids, key, num_regions, num_subsets, strategy):
+    key2 = kdtree._label_key(points, key, strategy, 0)
+    order = jnp.lexsort((key2, region_ids))
+    _, rank, _ = _oracle_segment_rank(region_ids, order, num_regions)
+    label_sorted = (rank % num_subsets).astype(jnp.int32)
+    return jnp.zeros_like(region_ids).at[order].set(label_sorted)
+
+
+def _points(n, ties, seed=0):
+    x = jax.random.normal(jax.random.key(seed), (n, 3))
+    # ties: four distinct values per axis, so most comparisons are equal
+    return jnp.floor(jnp.abs(x) * 2.0).clip(0, 3) if ties else x
+
+
+# (n, depth, forced ties): odd and even n, every depth 1-9
+_ORACLE_CASES = [(777, 1, False), (777, 4, True), (777, 9, False),
+                 (3001, 2, True), (3001, 7, False), (3001, 9, True),
+                 (1000, 3, False), (1000, 5, True),
+                 (4096, 6, True), (4096, 8, False)]
+
+
+@pytest.mark.parametrize("builder", ["sort", "histogram"])
+@pytest.mark.parametrize("strategy", ["axis", "random"])
+@pytest.mark.parametrize("n,depth,ties", _ORACLE_CASES)
+def test_sort_only_s1_matches_counting_oracle(n, depth, ties, strategy,
+                                              builder):
+    """Positional ranks and sort-restored point order give bit-identical
+    region ids and labels to lexsort + bincount + scatter, on leaves from
+    either builder."""
+    pts = _points(n, ties, seed=n + depth)
+    build = (kdtree.build_kdtree if builder == "sort"
+             else kdtree.build_kdtree_histogram)
+    region = build(pts, depth)
+    np.testing.assert_array_equal(np.asarray(region),
+                                  np.asarray(_oracle_build(pts, depth)))
+    key = jax.random.key(depth)
+    m = 7 if ties else 8                     # labels wrap mod M either way
+    got = kdtree.label_regions(pts, region, key, 2 ** depth, m,
+                               strategy=strategy)
+    want = _oracle_label(pts, region, key, 2 ** depth, m, strategy)
+    assert got.dtype == jnp.int32
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def _np_tree(x, depth):
+    region = np.zeros(len(x), np.int64)
+    for level in range(depth):
+        order = np.lexsort((x[:, level % x.shape[1]], region))
+        counts = np.bincount(region, minlength=2 ** level)
+        starts = np.cumsum(counts) - counts
+        sorted_seg = region[order]
+        rank = np.arange(len(x)) - starts[sorted_seg]
+        region[order] = sorted_seg * 2 + (rank >= (counts[sorted_seg] + 1) // 2)
+    return region
+
+
+@pytest.mark.parametrize("n", [1, 2, 3, 5, 64, 777, 1000, 3001, 4096])
+def test_median_segments_match_bincount(n):
+    """The start and size of each sorted position's segment, from the
+    position alone, equal those counted from a real tree's leaves."""
+    x = np.random.default_rng(n).standard_normal((n, 2))
+    for levels in range(0, 11):
+        sorted_leaf = np.sort(_np_tree(x, levels))
+        counts = np.bincount(sorted_leaf, minlength=2 ** levels)
+        starts = np.cumsum(counts) - counts
+        start, size = kdtree._median_segments(n, levels)
+        np.testing.assert_array_equal(np.asarray(start), starts[sorted_leaf])
+        np.testing.assert_array_equal(np.asarray(size), counts[sorted_leaf])
+
+
+def test_label_regions_needs_power_of_two_regions(pts):
+    region = kdtree.build_kdtree(pts, depth=2)
+    with pytest.raises(ValueError, match="power of two"):
+        kdtree.label_regions(pts, region, jax.random.key(0), 3, 4)
+
+
+_INDEXED_ACCESS = re.compile(r"\b(scatter|gather)\(")
+
+
+@pytest.mark.parametrize("fn", ["build_kdtree", "label_regions_axis",
+                                "label_regions_random"])
+def test_sort_only_s1_compiles_without_scatter_or_gather(fn):
+    """The compiled tree build and labeler move data by sorts alone: two a
+    level for the build, two for the labeler."""
+    depth, n = 6, 2 ** 12
+    pts = jax.ShapeDtypeStruct((n, 16), jnp.float32)
+    if fn == "build_kdtree":
+        lowered = kdtree.build_kdtree.lower(pts, depth)
+        sorts = 2 * depth
+    else:
+        region = jax.ShapeDtypeStruct((n,), jnp.int32)
+        lowered = kdtree.label_regions.lower(
+            pts, region, jax.random.key(0), 2 ** depth, 16,
+            strategy=fn.rsplit("_", 1)[1])
+        sorts = 2
+    text = lowered.compile().as_text()
+    assert not _INDEXED_ACCESS.search(text), _INDEXED_ACCESS.findall(text)
+    assert len(re.findall(r"\bsort\(", text)) == sorts
