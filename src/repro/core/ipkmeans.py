@@ -205,12 +205,13 @@ def _partition_and_pack(points, key, cfg: IPKMeansConfig,
     The shuffle strategy is ``cfg.pack`` (§Perf C2/C3 — previously
     reachable only from the kmeans_dryrun CLI):
 
-      * ``scatter`` — the reference scatter-pack; always valid.
-      * ``sorted``  — one sort + reshape, no scatter (GSPMD lowers the
-        scatter as a dataset-sized all-reduce; the sort+gather moves the
-        data once).  Requires every subset to hold exactly ``capacity``
-        points (``n == M * capacity``, the static precondition the kernel
-        itself asserts) — otherwise falls back to ``scatter``.
+      * ``scatter`` — the general pack (the name is historical): one sort
+        and one row gather, no scatter (:func:`kdtree.pack_subsets`);
+        always valid.
+      * ``sorted``  — one sort + reshape.  Requires every subset to hold
+        exactly ``capacity`` points (``n == M * capacity``, the static
+        precondition the kernel itself asserts) — otherwise falls back to
+        ``scatter``.
       * ``a2a``     — explicit shard_map all_to_all shuffle; needs a mesh
         (so the single-process :func:`ipkmeans` falls back to ``scatter``
         with a warning), and itself warns + falls back when M or n don't
@@ -262,8 +263,8 @@ def _partition_and_pack(points, key, cfg: IPKMeansConfig,
         else:
             if cfg.pack == "a2a":
                 warnings.warn(
-                    "pack='a2a' needs a device mesh; using the scatter pack "
-                    "(all-reduce-shaped collective) instead",
+                    "pack='a2a' needs a device mesh; using the gather pack "
+                    "(one sort and one row gather) instead",
                     RuntimeWarning, stacklevel=2)
             subsets, masks = kdtree.pack_subsets(
                 points, part.subset_ids, cfg.num_subsets, capacity)

@@ -15,7 +15,7 @@ MapReduce jobs.
 Everything is pure jnp and jit-safe for a static ``depth`` / ``num_subsets``,
 and — because sorts and scatters are SPMD-partitionable — runs sharded under
 pjit on a mesh without modification.  Past one pod that is no longer enough:
-GSPMD lowers the level sorts and the scatter pack as dataset-sized
+GSPMD lowers the level sorts and the pack's sort and gather as dataset-sized
 collectives over the slow DCN axis.  The ``*_sharded`` variants here run the
 same algorithms under ``shard_map`` with points sharded over
 ``(pods, devices)`` and exchange only O(regions * 256) histogram summaries
@@ -473,21 +473,45 @@ def pack_subsets(points: jnp.ndarray,
                  subset_ids: jnp.ndarray,
                  num_subsets: int,
                  capacity: int):
-    """Scatter points into a rectangular (M, capacity, d) tensor + bool mask.
+    """Gather points into a rectangular (M, capacity, d) tensor + bool mask.
 
-    This is the shuffle that routes each subset to its reducer.  Points beyond
-    ``capacity`` in a subset are dropped (cannot happen for kd-tree labeling
-    with capacity >= ceil(num_leaves * leaf_cap / M); asserted in tests).
+    This is the shuffle that routes each subset to its reducer: one stable
+    sort of the ids carries the point index, a search of the sorted ids
+    gives every subset's start and count, and slot (s, j) gathers the row
+    at sorted position ``starts[s] + j``.  Within a subset rows keep point
+    order; slots past a subset's count hold zeros with mask False; a subset
+    with more than ``capacity`` points loses its highest-index rows (cannot
+    happen for kd-tree labeling with capacity >= ceil(num_leaves * leaf_cap
+    / M); asserted in tests).  No scatter: under GSPMD the gather moves the
+    data once, where a scatter lowers as a dataset-sized all-reduce.
+
+    When every subset holds exactly ``capacity`` points (full kd leaves,
+    n == M * capacity), the sorted order reshaped is already the source
+    map, so the index arithmetic and its gather are skipped.
     """
-    n, d = points.shape
-    order = jnp.argsort(subset_ids, stable=True)
-    sorted_sub, rank, _ = _segment_rank(subset_ids, order, num_subsets)
-    out = jnp.zeros((num_subsets, capacity, d), points.dtype)
-    msk = jnp.zeros((num_subsets, capacity), bool)
-    # ranks >= capacity fall out of bounds and are dropped by mode='drop'
-    out = out.at[sorted_sub, rank].set(points[order], mode="drop")
-    msk = msk.at[sorted_sub, rank].set(True, mode="drop")
-    return out, msk
+    n = subset_ids.shape[0]              # ids label the first n points
+    ids, order = jax.lax.sort(
+        (subset_ids.astype(jnp.int32), jnp.arange(n, dtype=jnp.int32)),
+        num_keys=1)
+    starts = jnp.searchsorted(
+        ids, jnp.arange(num_subsets + 1, dtype=jnp.int32), method="scan")
+    counts = starts[1:] - starts[:-1]
+    slot = jnp.arange(capacity, dtype=jnp.int32)
+    mask = slot < counts[:, None]
+
+    def ragged_source(order):
+        pos = jnp.minimum(starts[:-1, None] + slot, n - 1)
+        # an empty slot reads row points.shape[0], out of range: zero fill
+        return jnp.where(mask, order[pos], points.shape[0])
+
+    if n == num_subsets * capacity:
+        src = jax.lax.cond(jnp.all(counts == capacity),
+                           lambda o: o.reshape(num_subsets, capacity),
+                           ragged_source, order)
+    else:
+        src = ragged_source(order)
+    packed = points.at[src].get(mode="fill", fill_value=0)
+    return packed, mask
 
 
 @partial(jax.jit, static_argnames=("num_subsets", "capacity"))
@@ -499,9 +523,9 @@ def pack_subsets_sorted(points: jnp.ndarray,
 
     Valid when every subset holds exactly ``capacity`` points (true for the
     kd-tree labeling whenever n == num_subsets * capacity, i.e. full
-    leaves).  GSPMD lowers the scatter in :func:`pack_subsets` as a
-    local-scatter + full-output ALL-REDUCE (a dataset-sized reduction);
-    the sort+gather formulation moves the data once instead — §Perf C2.
+    leaves).  GSPMD would lower a scatter pack as a local-scatter +
+    full-output ALL-REDUCE (a dataset-sized reduction); the sort+gather
+    formulation moves the data once instead — §Perf C2.
     """
     n, d = points.shape
     assert n == num_subsets * capacity, (n, num_subsets, capacity)
@@ -520,7 +544,7 @@ def pack_subsets_a2a(points: jnp.ndarray,
                      pod_axis: str | None = None):
     """Communication-optimal pack: explicit all_to_all shuffle (§Perf C3).
 
-    GSPMD lowers both the scatter- and the sort-based packs as dataset-
+    GSPMD lowers both the gather- and the sort-based packs as dataset-
     sized all-reduce/all-gather; but the shuffle's destinations are known
     (subset s lives on device s // (M/R)), so a capacity-padded shard_map
     all_to_all moves each point exactly once — the same dispatch pattern as
@@ -542,9 +566,9 @@ def pack_subsets_a2a(points: jnp.ndarray,
     ``dropped`` a replicated scalar count of points lost to slot or
     capacity overflow (0 in healthy configurations; callers should check).
 
-    Preconditions (else: warn + scatter fallback): ``num_subsets`` divides
-    by the in-pod device count, ``n`` by the total device count, and
-    ``capacity`` by the pod count.
+    Preconditions (else: warn + :func:`pack_subsets` fallback):
+    ``num_subsets`` divides by the in-pod device count, ``n`` by the total
+    device count, and ``capacity`` by the pod count.
     """
     from jax.sharding import PartitionSpec as P
 
@@ -561,8 +585,8 @@ def pack_subsets_a2a(points: jnp.ndarray,
         precondition = f"capacity={capacity} % pods={n_pods} != 0"
     if precondition:
         warnings.warn(
-            "pack_subsets_a2a: falling back to the scatter pack "
-            f"(all-reduce-shaped collective) because {precondition}",
+            "pack_subsets_a2a: falling back to the gather pack "
+            f"(pack_subsets) because {precondition}",
             RuntimeWarning, stacklevel=2)
         out, msk = pack_subsets(points, subset_ids, num_subsets, capacity)
         return out, msk, jnp.int32(n) - msk.sum(dtype=jnp.int32)

@@ -269,3 +269,88 @@ def test_sort_only_s1_compiles_without_scatter_or_gather(fn):
     text = lowered.compile().as_text()
     assert not _INDEXED_ACCESS.search(text), _INDEXED_ACCESS.findall(text)
     assert len(re.findall(r"\bsort\(", text)) == sorts
+
+
+# -- the gather pack against the scatter pack it replaced -------------------
+
+@partial(jax.jit, static_argnames=("num_subsets", "capacity"))
+def _oracle_pack(points, subset_ids, num_subsets, capacity):
+    """argsort + bincount ranks + row scatter into zeros, dropping ranks
+    past ``capacity``."""
+    order = jnp.argsort(subset_ids, stable=True)
+    sorted_sub, rank, _ = _oracle_segment_rank(subset_ids, order, num_subsets)
+    out = jnp.zeros((num_subsets, capacity, points.shape[1]), points.dtype)
+    msk = jnp.zeros((num_subsets, capacity), bool)
+    out = out.at[sorted_sub, rank].set(points[order], mode="drop")
+    msk = msk.at[sorted_sub, rank].set(True, mode="drop")
+    return out, msk
+
+
+def _pack_case(case, d):
+    """(points, subset ids, M, capacity) for one named case."""
+    rng = np.random.default_rng(abs(hash((case, d))) % 2 ** 32)
+    if case == "kd_full":                     # 256 leaves of 16, 16 subsets
+        n, m = 4096, 16
+    elif case == "random_partition":
+        n, m = 1000, 8
+    else:                                     # 777 / 2^7: leaves of 6 or 7
+        n, m = 777, 8
+    pts = jnp.asarray(rng.standard_normal((n, d)), jnp.float32)
+    key = jax.random.key(n + d)
+    if case in ("kd_full", "kd_uneven", "roomy"):
+        part = kdtree.partition_dataset(pts, key, m, leaf_capacity=16 if
+                                        case == "kd_full" else None)
+        ids = part.subset_ids
+    elif case == "random_partition":
+        ids = kdtree.partition_dataset(pts, key, m, strategy="random").subset_ids
+    elif case == "overflow":                  # uneven ids, n == M * capacity
+        n, m = 1024, 8
+        pts = jnp.asarray(rng.standard_normal((n, d)), jnp.float32)
+        ids = jnp.asarray(rng.integers(0, m, n), jnp.int32)
+        return pts, ids, m, n // m
+    else:                                     # "empty": odd subsets get none
+        ids = jnp.asarray(rng.integers(0, m // 2, n) * 2, jnp.int32)
+    largest = int(np.bincount(np.asarray(ids), minlength=m).max())
+    return pts, ids, m, largest + (5 if case == "roomy" else 0)
+
+
+# (case, whether every subset is full: the reshape branch of the cond)
+_PACK_CASES = [("kd_full", True), ("kd_uneven", False),
+               ("random_partition", True), ("overflow", False),
+               ("empty", False), ("roomy", False)]
+
+
+@pytest.mark.parametrize("d", [1, 128])
+@pytest.mark.parametrize("case,full", _PACK_CASES)
+def test_pack_matches_scatter_oracle(case, full, d):
+    """One sort and one row gather give the scatter pack's tensor and mask
+    in every bit: point order within a subset, zero rows past a subset's
+    count, overflow rows dropped from the highest index."""
+    pts, ids, m, cap = _pack_case(case, d)
+    counts = np.bincount(np.asarray(ids), minlength=m)
+    assert (pts.shape[0] == m * cap and (counts == cap).all()) == full
+    if case == "overflow":
+        assert counts.max() > cap
+    if case == "empty":
+        assert (counts[1::2] == 0).all()
+    packed, mask = kdtree.pack_subsets(pts, ids, m, cap)
+    want_p, want_m = _oracle_pack(pts, ids, m, cap)
+    assert packed.dtype == want_p.dtype and packed.shape == (m, cap, d)
+    np.testing.assert_array_equal(np.asarray(packed).view(np.uint32),
+                                  np.asarray(want_p).view(np.uint32))
+    np.testing.assert_array_equal(np.asarray(mask), np.asarray(want_m))
+
+
+@pytest.mark.parametrize("capacity", [1024, 1500])
+def test_pack_compiles_without_scatter(capacity):
+    """The compiled pack holds no scatter and one sort of n keys, with and
+    without the full-subset cond (n == M * capacity)."""
+    n, m = 2 ** 14, 16
+    lowered = kdtree.pack_subsets.lower(
+        jax.ShapeDtypeStruct((n, 128), jnp.float32),
+        jax.ShapeDtypeStruct((n,), jnp.int32), m, capacity)
+    text = lowered.compile().as_text()
+    assert "scatter(" not in text
+    sorts = [ln for ln in text.splitlines() if re.search(r"\bsort\(", ln)]
+    assert len(sorts) == 1, sorts
+    assert f"s32[{n}]" in sorts[0]
